@@ -29,25 +29,6 @@ def csv_row(name: str, us_per_call: float, derived: str):
     print(f"{name},{us_per_call:.1f},{derived}")
 
 
-def write_bench_json(name: str, payload: dict) -> str:
-    """Write a machine-readable ``BENCH_<name>.json`` snapshot at the repo
-    root (CI uploads them as artifacts; committed snapshots let future PRs
-    diff perf).  Environment metadata is attached so numbers from different
-    backends/device counts are never compared blindly."""
-    import json
-
-    path = os.path.join(os.path.dirname(__file__), "..",
-                        f"BENCH_{name}.json")
-    doc = {"meta": {"backend": jax.default_backend(),
-                    "device_count": jax.device_count(),
-                    "jax": jax.__version__},
-           **payload}
-    with open(path, "w") as f:
-        json.dump(doc, f, indent=2, sort_keys=True, default=float)
-        f.write("\n")
-    return os.path.abspath(path)
-
-
 def ensure_dir(*parts):
     p = os.path.join(RESULTS_DIR, *parts)
     os.makedirs(p, exist_ok=True)

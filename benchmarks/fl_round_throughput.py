@@ -39,7 +39,7 @@ from __future__ import annotations
 import argparse
 import os
 
-from benchmarks.common import csv_row, timeit, write_bench_json
+from benchmarks.common import csv_row, timeit
 
 
 def _force_host_devices(n: int):
@@ -302,32 +302,13 @@ def bench_conv_impl(num_cohorts: int = 16, batch_size: int = 4,
     return out
 
 
-def _merge_bench_json(payload: dict) -> str:
-    """Update keys of ``BENCH_fl_round.json`` in place, so a population
-    sweep and the backend-throughput run compose into one snapshot instead
-    of clobbering each other."""
-    import json
-
-    path = os.path.join(os.path.dirname(__file__), "..",
-                        "BENCH_fl_round.json")
-    doc = {}
-    if os.path.exists(path):
-        with open(path) as f:
-            doc = json.load(f)
-        doc.pop("meta", None)              # re-stamped by write_bench_json
-    doc.update(payload)
-    return write_bench_json("fl_round", doc)
-
-
 def quick():
-    rows = {}
     for kind in ("cnn", "transformer"):
         # fused flags on: the im2col convs + Pallas-nHSIC loss are the
         # paths CI must actually execute (ISSUE 6 bench-smoke)
         rps = bench(kind, num_cohorts=16, batch_size=4, local_steps=2,
                     conv_impl="im2col" if kind == "cnn" else None,
                     use_hsic_kernel=True)
-        rows[kind] = rps
         base = rps["sequential"]
         for name, r in rps.items():
             csv_row(f"fl_round_{kind}_{name}", 1e6 / r,
@@ -343,9 +324,6 @@ def quick():
                 1e6 / row["rounds_per_s"],
                 f"{row['rounds_per_s']:.2f}r/s "
                 f"{row['server_peak_mb']:.1f}MBpeak")
-    _merge_bench_json({"rounds_per_s": rows,
-                       "conv_impl_crossover_cnn": cross,
-                       "population_sweep_quick": sweep})
 
 
 def main():
@@ -377,8 +355,7 @@ def main():
                     metavar="N",
                     help="streaming-fleet sweep: time full rounds at these "
                          "population sizes (e.g. --population 1e2 1e3 1e4 "
-                         "1e5 1e6) at a fixed cohort; writes the "
-                         "population_sweep rows of BENCH_fl_round.json")
+                         "1e5 1e6) at a fixed cohort")
     ap.add_argument("--selection", choices=["random", "tifl", "oort"],
                     default="random",
                     help="cohort policy for the --population sweep")
@@ -396,7 +373,6 @@ def main():
         print(f"rounds/s at {last['population']} = "
               f"{last['rounds_per_s'] / base['rounds_per_s']:.2f}x of "
               f"{base['population']}")
-        _merge_bench_json({"population_sweep": sweep})
         return
     if args.runtime == "async":
         # async x sharded composition: --model-parallel K runs the async
@@ -434,12 +410,10 @@ def main():
                       f"{ratio:5.2f}x")
         return
     print(f"{'model':12s} {'backend':12s} {'rounds/s':>9s} {'speedup':>8s}")
-    rows = {}
     for kind in ("cnn", "transformer"):
         rps = bench(kind, args.cohorts, args.batch, args.steps, args.stage,
                     args.iters, conv_impl=args.conv_impl,
                     use_hsic_kernel=args.use_hsic_kernel)
-        rows[kind] = rps
         base = rps["sequential"]
         for name, r in rps.items():
             print(f"{kind:12s} {name:12s} {r:9.2f} {r / base:7.1f}x")
@@ -448,8 +422,6 @@ def main():
     print(f"{'cnn':12s} {'conv-impl':12s} im2col {cross['im2col']:.2f}r/s "
           f"vs lax {cross['lax']:.2f}r/s = {cross['speedup']:.2f}x "
           f"at {cross['num_cohorts']} cohorts")
-    write_bench_json("fl_round", {"rounds_per_s": rows,
-                                  "conv_impl_crossover_cnn": cross})
 
 
 if __name__ == "__main__":

@@ -11,8 +11,6 @@ naive autodiff path keeps live for the two centered Grams.
 Also times the lax-conv vs im2col unit conv (forward and backward) under
 ``vmap`` over per-cohort weights — the shape the vectorized FL round
 actually runs (see ``fl_round_throughput`` for the full-round crossover).
-
-Writes a machine-readable ``BENCH_kernels.json`` snapshot.
 """
 from __future__ import annotations
 
@@ -21,7 +19,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import csv_row, timeit, write_bench_json
+from benchmarks.common import csv_row, timeit
 from repro.core import hsic
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.hsic_gram import ops as kops
@@ -83,7 +81,7 @@ def _conv_rows(key, out, quiet):
                   f"fwd {row['fwd_s']*1e3:.2f}ms bwd {row['bwd_s']*1e3:.2f}ms")
 
 
-def run(quiet: bool = False, write_json: bool = True):
+def run(quiet: bool = False):
     key = jax.random.PRNGKey(0)
     out = {}
     # attention reference throughput (per-shape)
@@ -101,8 +99,6 @@ def run(quiet: bool = False, write_json: bool = True):
                   f"({flops/t/1e9:.1f} GFLOP/s)")
     _nhsic_rows(key, out, quiet)
     _conv_rows(key, out, quiet)
-    if write_json:
-        write_bench_json("kernels", {"rows": out})
     return out
 
 

@@ -12,21 +12,7 @@ See docs/analysis.md.
 from __future__ import annotations
 
 import argparse
-import json
 import sys
-
-
-def _merge_bench(path: str, section: str, key: str, payload) -> None:
-    try:
-        with open(path) as fh:
-            bench = json.load(fh)
-    except FileNotFoundError:
-        bench = {}
-    bench.setdefault(section, {})[key] = payload
-    with open(path, "w") as fh:
-        json.dump(bench, fh, indent=2, sort_keys=True)
-        fh.write("\n")
-    print(f"{section}[{key!r}] merged into {path}")
 
 
 def main(argv=None) -> int:
@@ -70,10 +56,6 @@ def main(argv=None) -> int:
     ap.add_argument("--json", metavar="PATH",
                     help="write the full report (findings + artifact "
                          "tables) as JSON")
-    ap.add_argument("--write-bench", metavar="PATH", nargs="?", const="",
-                    help="merge the audited static table into the bench "
-                         "JSON (default BENCH_fl_round.json, or "
-                         "BENCH_kernels.json under --kernels)")
     ap.add_argument("--verbose", action="store_true",
                     help="also print info-level findings")
     args = ap.parse_args(argv)
@@ -96,17 +78,6 @@ def main(argv=None) -> int:
     if args.json:
         report.dump_json(args.json)
         print(f"report written to {args.json}")
-    if args.write_bench is not None:
-        if args.kernels and "kernel_vmem" in report.artifacts:
-            _merge_bench(args.write_bench or "BENCH_kernels.json",
-                         "vmem_audit", "kernels",
-                         report.artifacts["kernel_vmem"])
-        elif not args.kernels and "memory" in report.artifacts:
-            key = (f"{args.arch}/{args.backend}"
-                   + (f"/mp{args.model_parallel}"
-                      if args.model_parallel > 1 else ""))
-            _merge_bench(args.write_bench or "BENCH_fl_round.json",
-                         "static_memory", key, report.artifacts["memory"])
     return 0 if report.ok() else 1
 
 

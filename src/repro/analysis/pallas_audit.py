@@ -20,8 +20,7 @@ WITHOUT running a kernel:
 * ``pallas.vmem-budget`` — per-grid-step bytes (all in/out blocks +
   scratch, VMEM and SMEM accounted separately) against a configurable
   per-platform budget (16 MiB TPU default); the per-kernel table is
-  exported as the ``kernel_vmem`` report artifact (and into
-  ``BENCH_kernels.json`` via ``--write-bench``).
+  exported as the ``kernel_vmem`` report artifact.
 * ``pallas.low-precision-accum`` — bf16/f16 operand blocks must
   accumulate in f32: an f32 scratch accumulator, an f32 output, or an
   explicit in-kernel upcast / ``preferred_element_type``.
@@ -33,7 +32,7 @@ blocks, batches smaller than one block, degenerate D=1, bf16 inputs —
 in interpret mode, so CPU CI exercises the exact kernel code path.
 
 CLI: ``python -m repro.analysis --kernels [--fuzz N] [--json PATH]
-[--waive CHECK] [--write-bench [PATH]]``; see docs/analysis.md.
+[--waive CHECK]``; see docs/analysis.md.
 """
 from __future__ import annotations
 

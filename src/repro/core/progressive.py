@@ -241,9 +241,12 @@ def make_stage_loss(adapter: Adapter, hp: cur.CurriculumHP, t: int):
     def loss_fn(trainable, frozen, batch, global_ref):
         logits, feats = adapter.stage_apply(frozen, trainable,
                                             batch["inputs"])
-        loss, metrics = cur.curriculum_loss(
-            logits, feats, batch, adapter.cfg, hp, t, T, adapter.num_classes)
-        prox = cur.proximal_term(trainable, global_ref, hp.mu)
+        # the cross-entropy of the logits comes with the nHSIC terms
+        with jax.named_scope("neulite.mentor"):
+            loss, metrics = cur.curriculum_loss(
+                logits, feats, batch, adapter.cfg, hp, t, T,
+                adapter.num_classes)
+            prox = cur.proximal_term(trainable, global_ref, hp.mu)
         metrics["prox"] = prox
         return loss + prox, metrics
 

@@ -102,6 +102,13 @@ class RoundStack:
     def max_steps(self) -> int:
         return int(self.step_mask.shape[1])
 
+    @property
+    def nbytes(self) -> int:
+        """Host bytes of the arrays a runtime copies to the device."""
+        import jax
+        return int(sum(np.asarray(x).nbytes for x in jax.tree.leaves(
+            (self.batches, self.step_mask, self.weights))))
+
 
 def _stack_trees(trees):
     import jax

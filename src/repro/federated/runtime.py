@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import spans
 from repro.core.curriculum import CurriculumHP
 from repro.core.progressive import (Adapter, jit_stage_step, make_full_step,
                                     make_stage_loss, make_stage_step)
@@ -84,11 +85,13 @@ def make_local_program(adapter: Adapter, optimizer, hp: CurriculumHP,
             opt_state, trainable = carry
             (loss, _), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(trainable, frozen, batch, trainable0)
-            updates, new_opt = optimizer.update(grads, opt_state, trainable)
-            new_tr = apply_updates(trainable, updates)
-            sel = lambda new, old: jnp.where(keep, new, old)
-            carry = (jax.tree.map(sel, new_opt, opt_state),
-                     jax.tree.map(sel, new_tr, trainable))
+            with jax.named_scope("neulite.optimizer"):
+                updates, new_opt = optimizer.update(grads, opt_state,
+                                                    trainable)
+                new_tr = apply_updates(trainable, updates)
+                sel = lambda new, old: jnp.where(keep, new, old)
+                carry = (jax.tree.map(sel, new_opt, opt_state),
+                         jax.tree.map(sel, new_tr, trainable))
             return carry, jnp.where(keep, loss, 0.0)
 
         (_, trainable), losses = jax.lax.scan(
@@ -124,20 +127,21 @@ def eq1_aggregate(locals_, weights, losses, *, axis: Optional[str] = None,
     contraction lowers under GSPMD to one all-reduce over the data axis
     per leaf while model shards keep owning their slice (no gather).
     """
-    if locals_shardings is not None:
-        locals_ = jax.lax.with_sharding_constraint(locals_,
-                                                   locals_shardings)
-    total = weights.sum().astype(jnp.float32)
-    if axis is not None:
-        total = jax.lax.psum(total, axis)
-    w = weights.astype(jnp.float32) / jnp.maximum(total, 1e-12)
-    # Eq. 1: weighted FedAvg over the trainable subtree only — this
-    # einsum over the cohort axis is the round's one all-reduce
-    new_trainable = jax.tree.map(
-        lambda leaf: _psum_if(jnp.einsum(
-            "c...,c->...", leaf.astype(jnp.float32), w), axis).astype(
-                leaf.dtype), locals_)
-    mean_loss = _psum_if(jnp.sum(losses * w), axis)
+    with jax.named_scope("neulite.eq1"):
+        if locals_shardings is not None:
+            locals_ = jax.lax.with_sharding_constraint(locals_,
+                                                       locals_shardings)
+        total = weights.sum().astype(jnp.float32)
+        if axis is not None:
+            total = jax.lax.psum(total, axis)
+        w = weights.astype(jnp.float32) / jnp.maximum(total, 1e-12)
+        # Eq. 1: weighted FedAvg over the trainable subtree only — this
+        # einsum over the cohort axis is the round's one all-reduce
+        new_trainable = jax.tree.map(
+            lambda leaf: _psum_if(jnp.einsum(
+                "c...,c->...", leaf.astype(jnp.float32), w), axis).astype(
+                    leaf.dtype), locals_)
+        mean_loss = _psum_if(jnp.sum(losses * w), axis)
     return new_trainable, mean_loss
 
 
@@ -393,7 +397,8 @@ class ClientRuntime:
         """One round on a prepared stack -> (new_trainable, metrics)."""
         if float(np.sum(stack.weights)) <= 0:
             raise ValueError("round has zero total aggregation weight")
-        frozen, trainable = self.adapter.split_stage(params, t)
+        with spans.span("fl.split"):
+            frozen, trainable = self.adapter.split_stage(params, t)
         return self._run_stack(t, trainable, frozen, stack)
 
     def _round_from_stack(self, params, t: int, stack: RoundStack,
@@ -417,9 +422,13 @@ class ClientRuntime:
         A round where every cohort crashed before step 0 is a lost round:
         params come back unchanged with a NaN loss.
         """
-        stack = stack_round(batchers, cohorts, local_epochs=local_epochs)
-        if faults is not None:
-            stack = truncate_step_mask(stack, faults)
+        with spans.span("fl.stack") as s:
+            stack = stack_round(batchers, cohorts, local_epochs=local_epochs)
+            if faults is not None:
+                stack = truncate_step_mask(stack, faults)
+            if s is not None:
+                s.attrs.update(C=stack.num_cohorts, E=stack.max_steps)
+            spans.count("stack_bytes", stack.nbytes)
         if float(np.sum(stack.weights)) <= 0:        # all cohorts dropped
             _, trainable = self.adapter.split_stage(params, t)
             return RoundOutcome(
@@ -435,8 +444,10 @@ class ClientRuntime:
         extras.setdefault(
             "n_uploads", int(np.count_nonzero(
                 np.asarray(stack.weights) > 0)))
+        with spans.span("fl.merge"):
+            params = self.adapter.merge_stage(params, new_trainable, t)
         return RoundOutcome(
-            params=self.adapter.merge_stage(params, new_trainable, t),
+            params=params,
             trainable=new_trainable,
             mean_loss=metrics["mean_local_loss"],
             cohort_losses=metrics["cohort_losses"],
@@ -523,7 +534,8 @@ class SequentialRuntime(ClientRuntime):
         if faults is not None:
             return ClientRuntime.run_round(self, params, t, batchers,
                                            cohorts, local_epochs, faults)
-        frozen, trainable = self.adapter.split_stage(params, t)
+        with spans.span("fl.split"):
+            frozen, trainable = self.adapter.split_stage(params, t)
         step = self._step(t)
         results, losses, num_batches, num_samples = [], [], [], []
         for cid in cohorts:
@@ -550,8 +562,10 @@ class SequentialRuntime(ClientRuntime):
         cohort_losses = jnp.stack([jnp.asarray(l) for l in losses])
         w = np.asarray(num_samples, np.float32)
         w = jnp.asarray(w / w.sum())
+        with spans.span("fl.merge"):
+            params = self.adapter.merge_stage(params, new_trainable, t)
         return RoundOutcome(
-            params=self.adapter.merge_stage(params, new_trainable, t),
+            params=params,
             trainable=new_trainable,
             mean_loss=(cohort_losses * w).sum(),
             cohort_losses=cohort_losses,
@@ -604,13 +618,17 @@ class VectorizedRuntime(ClientRuntime):
         return self._programs[t]
 
     def _device_stack(self, stack: RoundStack):
-        return (jax.tree.map(jnp.asarray, stack.batches),
-                jnp.asarray(stack.weights),
-                jnp.asarray(stack.step_mask))
+        with spans.span("fl.to_device"):
+            spans.count("h2d_bytes", stack.nbytes)
+            return (jax.tree.map(jnp.asarray, stack.batches),
+                    jnp.asarray(stack.weights),
+                    jnp.asarray(stack.step_mask))
 
     def _run_stack(self, t, trainable, frozen, stack: RoundStack):
         batches, weights, mask = self._device_stack(stack)
-        return self._program(t)(trainable, frozen, batches, weights, mask)
+        program = self._program(t)
+        with spans.span("fl.dispatch"):
+            return program(trainable, frozen, batches, weights, mask)
 
     # -- static-analysis registry ------------------------------------------ #
     def trace_specs(self, params, t, stack):
@@ -805,8 +823,9 @@ class ShardedRuntime(VectorizedRuntime):
             trainable, frozen, batches, weights, mask = \
                 self._place.place_inputs(t, trainable, frozen, batches,
                                          weights, mask)
-        new_trainable, metrics = program(trainable, frozen, batches,
-                                         weights, mask)
+        with spans.span("fl.dispatch"):
+            new_trainable, metrics = program(trainable, frozen, batches,
+                                             weights, mask)
         C = stack.num_cohorts
         metrics = dict(metrics,
                        cohort_losses=metrics["cohort_losses"][:C])
@@ -1359,7 +1378,8 @@ class AsyncBufferedRuntime(ClientRuntime):
         """
         if float(np.sum(stack.weights)) <= 0:
             raise ValueError("round has zero total aggregation weight")
-        frozen, trainable = self.adapter.split_stage(params, t)
+        with spans.span("fl.split"):
+            frozen, trainable = self.adapter.split_stage(params, t)
         return self._run_stack(t, trainable, frozen, stack,
                                sim_times=sim_times)
 
@@ -1367,15 +1387,19 @@ class AsyncBufferedRuntime(ClientRuntime):
         """Run the cohort-vmapped local program; returns (trainable as
         placed, (C,) locals stack, (C,) losses) with any mesh padding
         already stripped from the metrics axis."""
-        batches = jax.tree.map(jnp.asarray, stack.batches)
-        mask = jnp.asarray(stack.step_mask)
+        with spans.span("fl.to_device"):
+            spans.count("h2d_bytes", stack.nbytes)
+            batches = jax.tree.map(jnp.asarray, stack.batches)
+            mask = jnp.asarray(stack.step_mask)
         if self.mesh is not None:
             batches, _, mask = pad_cohorts(
                 batches, jnp.asarray(stack.weights), mask,
                 self.mesh.shape[self.axis])
             trainable, frozen, batches, _, mask = self._place.place_inputs(
                 t, trainable, frozen, batches, None, mask)
-        locals_, losses = self._program(t)(trainable, frozen, batches, mask)
+        program = self._program(t)
+        with spans.span("fl.dispatch"):
+            locals_, losses = program(trainable, frozen, batches, mask)
         return trainable, locals_, losses
 
     def _run_stack(self, t, trainable, frozen, stack: RoundStack, *,

@@ -29,6 +29,7 @@ import numpy as np
 from repro import optim
 from repro.checkpoint import (latest_checkpoint, load_checkpoint,
                               read_checkpoint_meta, save_checkpoint)
+from repro.common import spans
 from repro.core import (CurriculumHP, PlateauSchedule, RoundRobinSchedule,
                         SequentialSchedule)
 from repro.core.memory import estimate_full_memory, estimate_stage_memory
@@ -221,27 +222,37 @@ class NeuLiteServer:
 
     # ------------------------------------------------------------------ #
     def run_round(self, r: int) -> RoundResult:
-        flc = self.flc
-        t = self.schedule.stage(r)
-        state = getattr(self.runtime, "state", None)
-        if state is not None and not getattr(self.schedule,
-                                             "revisits_stages", True):
-            # monotone schedule: stages before t never train again, so
-            # their pending async deltas are permanently unusable — retire
-            # them instead of stranding them in the buffer for the run
-            state.drop_retired_stages(t)
-        req = self.stage_mem_requirement(t)
-        selected, n_feasible = self.selector.select(
-            self.rng, self.fleet, flc.clients_per_round, req, r)
+        with spans.span("fl.round", round=r) as rs:
+            rr = self._run_round(r)
+            if rs is not None:
+                rs.attrs["stage"] = rr.stage
+        return rr
 
-        if selected:
+    def _run_round(self, r: int) -> RoundResult:
+        flc = self.flc
+        with spans.span("fl.open") as opening:
+            t = self.schedule.stage(r)
+            state = getattr(self.runtime, "state", None)
+            if state is not None and not getattr(self.schedule,
+                                                 "revisits_stages", True):
+                # monotone schedule: stages before t never train again, so
+                # their pending async deltas are permanently unusable —
+                # retire them instead of stranding them in the buffer
+                state.drop_retired_stages(t)
+            req = self.stage_mem_requirement(t)
+            selected, n_feasible = self.selector.select(
+                self.rng, self.fleet, flc.clients_per_round, req, r)
             faults = None
             prob = dropout_prob(flc.dropout_schedule, flc.dropout_rate, r)
-            if prob > 0:
+            if selected and prob > 0:
                 targets = [flc.local_epochs
                            * self.batchers[cid].steps_per_epoch
                            for cid in selected]
                 faults = sample_fault_steps(self.rng, targets, prob)
+            if opening is not None:
+                opening.attrs["n_selected"] = len(selected)
+
+        if selected:
             out = self.runtime.run_round(self.params, t, self.batchers,
                                          selected, flc.local_epochs,
                                          faults=faults)
@@ -257,8 +268,9 @@ class NeuLiteServer:
             # the round's ONE host sync: mean loss and the per-cohort
             # losses the selection policy needs come over together
             # (hostsync audit gates this — see repro.analysis)
-            mean_loss_h, cohort_losses_h = jax.device_get(
-                (out.mean_loss, out.cohort_losses))
+            with spans.span("fl.sync"):
+                mean_loss_h, cohort_losses_h = jax.device_get(
+                    (out.mean_loss, out.cohort_losses))
             mean_loss = float(mean_loss_h)
             if out.round_sim_time is not None:
                 # async: the round spans from open to its last buffer flush
@@ -278,7 +290,8 @@ class NeuLiteServer:
 
         acc = None
         if self.test_batcher is not None:
-            acc = self.evaluate()
+            with spans.span("fl.evaluate"):
+                acc = self.evaluate()
             self.schedule.observe(r, 1.0 - acc)
         else:
             self.schedule.observe(r, mean_loss)
@@ -312,7 +325,8 @@ class NeuLiteServer:
                       f"feasible {rr.n_feasible}/{self.flc.n_devices}")
             if (flc.checkpoint_dir is not None and flc.checkpoint_every > 0
                     and (r + 1) % flc.checkpoint_every == 0):
-                self.save_state(flc.checkpoint_dir)
+                with spans.span("fl.checkpoint"):
+                    self.save_state(flc.checkpoint_dir)
         return self.history
 
     # ------------------------------------------------------------------ #

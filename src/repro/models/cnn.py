@@ -301,23 +301,29 @@ def cnn_stage_apply(frozen, trainable, cfg: CNNConfig, metas_split, inputs):
     Returns (logits, feats) in the same format as model.stage_apply."""
     x = inputs["images"]
     if frozen.get("units"):
-        xf = cnn_apply_units(cfg, metas_split["prefix"],
-                             jax.lax.stop_gradient(frozen["units"]), x)
-        x = jax.lax.stop_gradient(xf)
+        with jax.named_scope("neulite.prefix"):
+            xf = cnn_apply_units(cfg, metas_split["prefix"],
+                                 jax.lax.stop_gradient(frozen["units"]), x)
+            x = jax.lax.stop_gradient(xf)
     x_embed = x
-    if trainable.get("boundary_units"):
-        x = cnn_apply_units(cfg, metas_split["boundary"],
-                            trainable["boundary_units"], x)
-    x = cnn_apply_units(cfg, metas_split["active"], trainable["units"], x)
+    with jax.named_scope("neulite.block"):
+        if trainable.get("boundary_units"):
+            x = cnn_apply_units(cfg, metas_split["boundary"],
+                                trainable["boundary_units"], x)
+        x = cnn_apply_units(cfg, metas_split["active"], trainable["units"],
+                            x)
     z_active = x
     if trainable.get("surrogates"):
-        x = cnn_apply_surrogates(cfg, trainable["surrogates"], x)
-    pooled = jnp.mean(x, axis=(1, 2))
-    logits = linear(trainable["head"], pooled)
-    z_pooled = jnp.mean(z_active, axis=(1, 2))
+        with jax.named_scope("neulite.mentor"):
+            x = cnn_apply_surrogates(cfg, trainable["surrogates"], x)
+    with jax.named_scope("neulite.block"):
+        pooled = jnp.mean(x, axis=(1, 2))
+        logits = linear(trainable["head"], pooled)
     z_proj = None
     if trainable.get("projector") is not None:
-        z_proj = cnn_apply_projector(trainable["projector"], z_pooled)
+        with jax.named_scope("neulite.mentor"):
+            z_pooled = jnp.mean(z_active, axis=(1, 2))
+            z_proj = cnn_apply_projector(trainable["projector"], z_pooled)
     feats = {"x_embed": x_embed, "z_active": z_active, "z_proj": z_proj,
              "aux": None, "loss_mask": None}
     return logits, feats

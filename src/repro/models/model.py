@@ -383,9 +383,11 @@ def stage_apply(frozen, trainable, cfg: ModelConfig, inputs: dict, *,
     """
     embed_params = trainable.get("embed", frozen.get("embed"))
     holder = {"embed": embed_params}
-    x, positions, loss_mask = embed_inputs(holder, cfg, inputs)
-    if "embed" in frozen:
-        x = jax.lax.stop_gradient(x)
+    with jax.named_scope("neulite.prefix" if "embed" in frozen
+                         else "neulite.block"):
+        x, positions, loss_mask = embed_inputs(holder, cfg, inputs)
+        if "embed" in frozen:
+            x = jax.lax.stop_gradient(x)
     x_embed = x
 
     aux = {"load_balance": jnp.zeros((), jnp.float32),
@@ -398,29 +400,34 @@ def stage_apply(frozen, trainable, cfg: ModelConfig, inputs: dict, *,
         return bool(leaves) and leaves[0].shape[0] > 0
 
     if has_periods(frozen.get("prefix")):
-        fro = jax.lax.stop_gradient(frozen["prefix"])
-        x, _, _ = _run_periods(fro, cfg, x, positions, remat=False,
-                               collect_aux=False)
-        x = jax.lax.stop_gradient(x)
-    if has_periods(trainable.get("boundary")):
-        x, _, a = _run_periods(trainable["boundary"], cfg, x, positions,
+        with jax.named_scope("neulite.prefix"):
+            fro = jax.lax.stop_gradient(frozen["prefix"])
+            x, _, _ = _run_periods(fro, cfg, x, positions, remat=False,
+                                   collect_aux=False)
+            x = jax.lax.stop_gradient(x)
+    with jax.named_scope("neulite.block"):
+        if has_periods(trainable.get("boundary")):
+            x, _, a = _run_periods(trainable["boundary"], cfg, x, positions,
+                                   remat=remat)
+            aux = {k: aux[k] + v for k, v in a.items()}
+        x, _, a = _run_periods(trainable["active"], cfg, x, positions,
                                remat=remat)
         aux = {k: aux[k] + v for k, v in a.items()}
-    x, _, a = _run_periods(trainable["active"], cfg, x, positions,
-                           remat=remat)
-    aux = {k: aux[k] + v for k, v in a.items()}
     z_active = x
 
     if has_periods(trainable.get("surrogates")):
-        x = apply_surrogates(trainable["surrogates"], cfg, x)
-    x = rmsnorm(trainable["final_norm"], x, cfg.norm_eps)
-    if cfg.task == "classify":
-        x = jnp.mean(x, axis=1)
-    logits = lm_head(trainable["head"], x, cfg.num_output_heads)
+        with jax.named_scope("neulite.mentor"):
+            x = apply_surrogates(trainable["surrogates"], cfg, x)
+    with jax.named_scope("neulite.block"):
+        x = rmsnorm(trainable["final_norm"], x, cfg.norm_eps)
+        if cfg.task == "classify":
+            x = jnp.mean(x, axis=1)
+        logits = lm_head(trainable["head"], x, cfg.num_output_heads)
 
     z_proj = None
     if trainable.get("projector") is not None:
-        z_proj = apply_projector(trainable["projector"], z_active)
+        with jax.named_scope("neulite.mentor"):
+            z_proj = apply_projector(trainable["projector"], z_active)
 
     feats = {"x_embed": x_embed, "z_active": z_active, "z_proj": z_proj,
              "aux": aux, "loss_mask": loss_mask}
