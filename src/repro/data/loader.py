@@ -4,14 +4,18 @@
 per epoch; datasets smaller than one batch are filled by resampling).
 ``stack_round`` materializes the ``(C, E, ...)`` cohort batch stack that
 the vectorized / mesh-sharded ``ClientRuntime`` backends consume as one
-array program input.
+array program input, writing each batch once: into fresh arrays, or into
+a ``StackBuffer`` that a runtime owns and refills every round.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import List, Optional, Sequence
 
 import numpy as np
+
+from repro.common import spans
 
 
 class Batcher:
@@ -88,6 +92,10 @@ class RoundStack:
                 exact no-ops for params and optimizer state
     weights   : (C,) float32 — true per-cohort sample counts (Eq. 1 weights)
     num_batches : true (unpadded) local step count per cohort
+
+    A stack ``stack_round`` returns without a buffer is never written
+    again; one built in a runtime's ``StackBuffer`` is overwritten by the
+    runtime's next round.
     """
     batches: dict
     step_mask: np.ndarray
@@ -110,23 +118,87 @@ class RoundStack:
             (self.batches, self.step_mask, self.weights))))
 
 
-def _stack_trees(trees):
-    import jax
-    return jax.tree.map(lambda *xs: np.stack(xs), *trees)
+_ALIGN = 64       # bytes; the CPU backend's arrays may alias aligned memory
+
+
+class StackBuffer:
+    """Host memory a runtime reuses for its round stacks.
+
+    One flat, grow-only byte array per batch leaf, 64-byte aligned; a
+    round's leaf is a C-contiguous ``(C, E, ...)`` view of the array's
+    first bytes, so its pages are mapped once, not every round.  Each round
+    overwrites the last one's stack, so a runtime passes its buffer only
+    where it consumes the stack within the round.
+
+    ``hold`` takes device arrays computed from the last stack: the round
+    program's outputs.  Before the next stack is written, those not deleted
+    are waited for, so the host-to-device copy, and on the CPU, where
+    ``jnp.asarray`` may alias host memory, the program that read the stack,
+    have finished.  Under ``spans.recording()`` a round that grows
+    the buffer adds the new bytes to ``stack_buffer_grown_bytes``; one that
+    fits adds 1 to ``stack_buffer_reused``.
+    """
+
+    def __init__(self):
+        self._flat: List[np.ndarray] = []
+        self._held: list = []
+
+    def hold(self, *trees) -> None:
+        import jax
+        self._held = jax.tree.leaves(trees)
+
+    def arrays(self, specs) -> List[np.ndarray]:
+        """Views of shape and dtype ``specs[i]`` for leaf ``i``, once the
+        held arrays are ready."""
+        import jax
+        jax.block_until_ready([x for x in self._held if not x.is_deleted()])
+        self._held = []
+        out, grown = [], 0
+        for i, (shape, dtype) in enumerate(specs):
+            need = math.prod(shape) * np.dtype(dtype).itemsize
+            if i == len(self._flat):
+                self._flat.append(np.empty(0, np.uint8))
+            have = self._flat[i].nbytes
+            if have < need:
+                raw = np.empty(need + _ALIGN, np.uint8)
+                start = -raw.ctypes.data % _ALIGN
+                self._flat[i] = raw[start:start + need]
+                grown += need - have
+            out.append(self._flat[i][:need].view(dtype).reshape(shape))
+        if grown:
+            spans.count("stack_buffer_grown_bytes", grown)
+        else:
+            spans.count("stack_buffer_reused", 1)
+        return out
+
+
+def _allocate(first_batch, lead, buffer: Optional[StackBuffer]):
+    specs = [(lead + np.shape(x), np.asarray(x).dtype) for x in first_batch]
+    if buffer is None:
+        return [np.empty(shape, dtype) for shape, dtype in specs]
+    return buffer.arrays(specs)
 
 
 def stack_round(batchers: Sequence[Batcher],
                 cohorts: Optional[Sequence[int]] = None,
                 local_steps: Optional[int] = None, *,
-                local_epochs: Optional[int] = None) -> RoundStack:
+                local_epochs: Optional[int] = None,
+                buffer: Optional[StackBuffer] = None) -> RoundStack:
     """Materialize the (C, E, ...) batch stack for a vectorized FL round.
 
     cohorts selects which batchers participate (default: all).  Pass either
     ``local_steps`` (uniform step count per cohort) or ``local_epochs``
     (each cohort runs ``local_epochs * steps_per_epoch`` true steps — the
     sequential reference semantics).  Cohorts with fewer true steps than the
-    widest cohort are padded with repeated batches masked out of training.
+    widest cohort are padded with repeats of their last batch, masked out of
+    training.
+
+    The batchers are walked cohort by cohort, each batch written once into
+    its ``(c, e)`` slot.  Without ``buffer`` the stack is fresh arrays that
+    nothing writes again; with one, it is views into the buffer that the
+    next call with the same buffer overwrites.
     """
+    import jax
     if (local_steps is None) == (local_epochs is None):
         raise ValueError("pass exactly one of local_steps / local_epochs")
     if cohorts is None:
@@ -137,18 +209,31 @@ def stack_round(batchers: Sequence[Batcher],
 
     targets = [local_steps if local_steps is not None
                else local_epochs * b.steps_per_epoch for b in picked]
-    E = max(targets)
+    if min(targets) < 1:
+        raise ValueError(f"every cohort needs a local step: {targets}")
+    C, E = len(picked), max(targets)
 
-    per_cohort, mask_rows = [], []
-    for b, tgt in zip(picked, targets):
-        seq = list(b.batches(tgt))
-        seq.extend(seq[-1] for _ in range(E - tgt))      # masked padding
-        per_cohort.append(_stack_trees(seq))
-        mask_rows.append([True] * tgt + [False] * (E - tgt))
+    treedef, out = None, None
+    for c, (b, tgt) in enumerate(zip(picked, targets)):
+        for e, batch in enumerate(b.batches(tgt)):
+            leaves, tdef = jax.tree.flatten(batch)
+            if out is None:
+                treedef, out = tdef, _allocate(leaves, (C, E), buffer)
+            elif tdef != treedef:
+                raise ValueError(f"batch structure {tdef} is not {treedef}")
+            for o, x in zip(out, leaves):
+                x = np.asarray(x)
+                if x.shape != o.shape[2:] or x.dtype != o.dtype:
+                    raise ValueError(
+                        f"batch leaf {x.dtype}{list(x.shape)} does not "
+                        f"match the stack's {o.dtype}{list(o.shape[2:])}")
+                o[c, e] = x
+        for o in out:
+            o[c, tgt:] = o[c, tgt - 1]                   # masked padding
 
     return RoundStack(
-        batches=_stack_trees(per_cohort),
-        step_mask=np.asarray(mask_rows, bool),
+        batches=jax.tree.unflatten(treedef, out),
+        step_mask=np.arange(E) < np.asarray(targets)[:, None],
         weights=np.asarray([b.num_samples for b in picked], np.float32),
         num_batches=[int(t) for t in targets])
 

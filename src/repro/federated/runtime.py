@@ -53,8 +53,8 @@ from repro.common import spans
 from repro.core.curriculum import CurriculumHP
 from repro.core.progressive import (Adapter, jit_stage_step, make_full_step,
                                     make_stage_loss, make_stage_step)
-from repro.data.loader import (Batcher, RoundStack, stack_round,
-                               truncate_step_mask)
+from repro.data.loader import (Batcher, RoundStack, StackBuffer,
+                               stack_round, truncate_step_mask)
 from repro.federated import aggregation as agg
 from repro.federated.client import run_local_training
 from repro.optim import apply_updates
@@ -379,9 +379,15 @@ class ClientRuntime:
         self.hp = hp
         self._programs: Dict[int, Any] = {}
 
-    # -- backend hook ------------------------------------------------------ #
+    # -- backend hooks ----------------------------------------------------- #
     def _run_stack(self, t: int, trainable, frozen, stack: RoundStack):
         raise NotImplementedError
+
+    def _round_buffer(self) -> Optional[StackBuffer]:
+        """Host memory ``run_round`` stacks into, reused round to round;
+        ``None`` (fresh arrays) unless the backend consumes the stack
+        within the round."""
+        return None
 
     def _lost_round_extras(self, stack: RoundStack,
                            cohorts: Sequence[int]) -> dict:
@@ -422,8 +428,10 @@ class ClientRuntime:
         A round where every cohort crashed before step 0 is a lost round:
         params come back unchanged with a NaN loss.
         """
+        buffer = self._round_buffer()
         with spans.span("fl.stack") as s:
-            stack = stack_round(batchers, cohorts, local_epochs=local_epochs)
+            stack = stack_round(batchers, cohorts, local_epochs=local_epochs,
+                                buffer=buffer)
             if faults is not None:
                 stack = truncate_step_mask(stack, faults)
             if s is not None:
@@ -441,6 +449,8 @@ class ClientRuntime:
                 **self._lost_round_extras(stack, cohorts))
         new_trainable, metrics, extras = self._round_from_stack(
             params, t, stack, cohorts)
+        if buffer is not None:
+            buffer.hold(new_trainable, metrics)
         extras.setdefault(
             "n_uploads", int(np.count_nonzero(
                 np.asarray(stack.weights) > 0)))
@@ -605,9 +615,20 @@ class VectorizedRuntime(ClientRuntime):
 
     The (C, E, ...) batch stack is donated to the program — it is rebuilt
     from host data every round, so XLA may reuse its buffers in place.
+    ``run_round`` builds that stack in one ``StackBuffer`` the runtime
+    keeps: the next round waits for this round's program before it writes
+    the buffer again, which costs the server nothing, since it syncs on
+    every round's loss.
     """
 
     name = "vectorized"
+
+    def __init__(self, adapter: Adapter, optimizer, hp: CurriculumHP):
+        super().__init__(adapter, optimizer, hp)
+        self._buffer = StackBuffer()
+
+    def _round_buffer(self) -> StackBuffer:
+        return self._buffer
 
     def _program(self, t: int):
         if t not in self._programs:

@@ -297,6 +297,73 @@ def test_zero_weight_round_rejected(cnn_setup):
 
 
 # --------------------------------------------------------------------------- #
+# the vectorized runtime's reused stack buffer
+# --------------------------------------------------------------------------- #
+class _UnbufferedVectorized(VectorizedRuntime):
+    def _round_buffer(self):
+        return None
+
+
+def test_round_buffer_only_where_the_round_consumes_the_stack(cnn_setup):
+    adapter, _, _ = cnn_setup
+    opt, hp = sgd(0.05), CurriculumHP()
+    assert SequentialRuntime(adapter, opt, hp)._round_buffer() is None
+    assert AsyncBufferedRuntime(adapter, opt, hp)._round_buffer() is None
+    vec = VectorizedRuntime(adapter, opt, hp)
+    assert vec._round_buffer() is vec._round_buffer() is not None
+    sh = ShardedRuntime(adapter, opt, hp)
+    assert sh._round_buffer() is not vec._round_buffer()
+
+
+def test_reused_stack_buffer_rounds_bit_identical_to_fresh(cnn_setup):
+    """Rounds whose widest cohort grows, shrinks and grows again give the
+    same params and losses whether the stack is written into the runtime's
+    buffer (grown twice, then reused) or into fresh arrays.  On the CPU
+    the device arrays may alias the buffer, so this also shows the buffer
+    is not refilled while the last round can still read it."""
+    import copy
+
+    from repro.common import spans
+    adapter, params, batchers = cnn_setup
+    opt, hp = sgd(0.05, momentum=0.9), CurriculumHP(mu=0.01)
+    vec = VectorizedRuntime(adapter, opt, hp)
+    plain = _UnbufferedVectorized(adapter, opt, hp)
+    plain._programs = vec._programs                 # compile each E once
+    mine, theirs = copy.deepcopy(batchers), copy.deepcopy(batchers)
+    kept = stack_round(copy.deepcopy(batchers), local_epochs=1)
+    before = jax.tree.map(np.copy, kept.batches)
+    vec.run_stacked(params, 0, kept)                # fresh stack, on device
+
+    cohorts, schedule = [0, 1, 2], [1, 2, 1, 2]     # E up, down, repeat
+    per_sample = 8 * 8 * 3 * 4 + 4                  # image f32 + label i32
+    outs, counted, p = [], [], params
+    for epochs in schedule:                         # no host sync between
+        with spans.recording() as rec:
+            outs.append(vec.run_round(p, 0, mine, cohorts, epochs))
+        p = outs[-1].params
+        counted.append({k: v for (k, _), v in rec.counters.items()
+                        if k.startswith("stack_buffer")})
+    p, had = params, 0
+    for r, (out, epochs) in enumerate(zip(outs, schedule)):
+        ref = plain.run_round(p, 0, theirs, cohorts, epochs)
+        p = ref.params
+        for x, y in zip(jax.tree.leaves((out.params, out.mean_loss,
+                                         out.cohort_losses)),
+                        jax.tree.leaves((ref.params, ref.mean_loss,
+                                         ref.cohort_losses))):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        assert out.num_batches == ref.num_batches
+        need = len(cohorts) * max(out.num_batches) * 16 * per_sample
+        if r in (0, 1):
+            assert counted[r] == {"stack_buffer_grown_bytes": need - had}
+            had = need
+        else:
+            assert counted[r] == {"stack_buffer_reused": 1}
+    for x, y in zip(jax.tree.leaves(kept.batches), jax.tree.leaves(before)):
+        np.testing.assert_array_equal(x, y)
+
+
+# --------------------------------------------------------------------------- #
 # aggregation einsum path
 # --------------------------------------------------------------------------- #
 def test_weighted_average_zero_sum_raises():
